@@ -33,6 +33,7 @@ from repro.core.lsp import (
     jit_search,
     make_dynamic_runner,
     mask_beyond_k,
+    split_arrays,
 )
 from repro.core.query import QueryBatch
 from repro.index.layout import LSPIndex
@@ -144,13 +145,16 @@ def exact_backend(
     scfg = static_cfg
     defaults = (defaults or DynamicParams(k=scfg.k_max)).validate_for(scfg)
     traces = {"n": 0}
+    arrays, rebuild = split_arrays(index)
 
     @jax.jit
-    def fn(tids, ws, k, mu, eta, beta):
+    def fn(arrays, tids, ws, k, mu, eta, beta):
         traces["n"] += 1
-        ids, vals = retrieve_exact(index, QueryBatch(tids, ws, vocab), scfg.k_max, doc_chunk)
+        ids, vals = retrieve_exact(
+            rebuild(arrays), QueryBatch(tids, ws, vocab), scfg.k_max, doc_chunk
+        )
         vals, ids = mask_beyond_k(vals, ids.astype(jnp.int32), k, scfg.k_max)
         zeros = jnp.zeros(tids.shape[0], jnp.int32)
         return RetrievalResult(ids, vals, zeros, zeros, theta=zeros.astype(jnp.float32))
 
-    return make_dynamic_runner(fn, scfg, defaults, vocab, traces)
+    return make_dynamic_runner(fn, arrays, scfg, defaults, vocab, traces)

@@ -7,9 +7,10 @@ index-build + query-time configurations used by benchmarks and the serve example
 from repro.core.config import RetrievalConfig
 from repro.index.builder import IndexBuildConfig
 
-# index-build recommendations (paper §Conclusion): c=16, small b, 4-bit bounds, Fwd docs
-INDEX_K10 = IndexBuildConfig(b=16, c=16, bound_bits=4, doc_bits=8)
-INDEX_K1000 = IndexBuildConfig(b=8, c=16, bound_bits=4, doc_bits=8)
+# index-build recommendations (paper §Conclusion): c=16, small b, 4-bit bounds, Fwd docs;
+# doc rows padded to full 128-lane TPU tiles for the doc_score kernels
+INDEX_K10 = IndexBuildConfig(b=16, c=16, bound_bits=4, doc_bits=8, lane_pad=128)
+INDEX_K1000 = IndexBuildConfig(b=8, c=16, bound_bits=4, doc_bits=8, lane_pad=128)
 
 # zero-shot query-time configs (no grid search)
 QUERY_K10 = RetrievalConfig(variant="lsp0", k=10, gamma=250, beta=0.33)

@@ -45,6 +45,7 @@ from typing import NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import ops
 from repro.core.config import (
@@ -153,16 +154,16 @@ def competitive_block_topk(
     return bvals, jnp.where(mask, gids, 0), mask
 
 
-def _score_blocks_dispatch(index, qdense, blk_ids, blk_mask, scfg, impl):
+def _score_blocks_dispatch(index, qb_full, blk_ids, blk_mask, scfg, impl):
     """Layout + impl routing for both scoring rounds, including the legacy baseline."""
     if impl == "legacy":
         b = index.b
         pos = blk_ids[:, :, None] * b + jnp.arange(b)[None, None, :]
         pos = pos.reshape(pos.shape[0], -1)
-        scores = score_positions_fwd(index, qdense, pos)
+        scores = score_positions_fwd(index, scatter_dense(qb_full), pos)
         mask = jnp.repeat(blk_mask, b, axis=1)
         return jnp.where(mask, scores, NEG), pos
-    return score_blocks(index, qdense, blk_ids, blk_mask, scfg.doc_layout, impl)
+    return score_blocks(index, qb_full, blk_ids, blk_mask, scfg.doc_layout, impl)
 
 
 _IMPLS = ("auto", "ref", "kernel", "legacy")
@@ -202,7 +203,6 @@ def search_retrieve(
     # budget wide); clamping here keeps the visited-superblock accounting honest
     g0 = min(scfg.gamma0, gamma, budget)
     qb = prune_terms(qb_full, d.beta)
-    qdense = scatter_dense(qb_full)
 
     # ---- phase 1: superblock bounds (paper Eq. 1), full sorted candidate list
     sbmax = ops.sbmax(index.sb_bounds, qb.tids, qb.ws, bounds_impl)  # [Q, NS]
@@ -211,7 +211,7 @@ def search_retrieve(
     # ---- round 0: seed θ from the guaranteed head of the list
     blk0 = _expand_superblocks(top_idx[:, :g0], c)  # [Q, g0*c]
     scores0, pos0 = _score_blocks_dispatch(
-        index, qdense, blk0, jnp.ones_like(blk0, bool), scfg, impl
+        index, qb_full, blk0, jnp.ones_like(blk0, bool), scfg, impl
     )
     theta = _kth_threshold(scores0, d.k, scfg.k_max, legacy=impl == "legacy")  # [Q]
 
@@ -265,7 +265,7 @@ def search_retrieve(
         blk_mask = bvals > NEG / 2
 
     # ---- phase 3: document scoring
-    scores1, pos1 = _score_blocks_dispatch(index, qdense, blk_ids, blk_mask, scfg, impl)
+    scores1, pos1 = _score_blocks_dispatch(index, qb_full, blk_ids, blk_mask, scfg, impl)
 
     # ---- merge rounds, final top-k. Canonical (score desc, doc-id asc) selection:
     # equal-score ties at the k boundary resolve by global doc id, not by traversal
@@ -310,19 +310,18 @@ def _retrieve_bmp(
     nb, b = index.n_blocks, index.b
     bounds_impl = "ref" if impl == "legacy" else impl
     qb = prune_terms(qb_full, d.beta)
-    qdense = scatter_dense(qb_full)
 
     boundsum = ops.sbmax(index.blk_bounds, qb.tids, qb.ws, bounds_impl)  # [Q, NB]
     b0 = min(max(scfg.gamma0 * index.c, scfg.k_max // b + 1), nb)
     v0, i0 = jax.lax.top_k(boundsum, b0)
-    scores0, pos0 = _score_blocks_dispatch(index, qdense, i0, jnp.ones_like(i0, bool), scfg, impl)
+    scores0, pos0 = _score_blocks_dispatch(index, qb_full, i0, jnp.ones_like(i0, bool), scfg, impl)
     theta = _kth_threshold(scores0, d.k, scfg.k_max, legacy=impl == "legacy")
 
     budget = resolve_block_budget(scfg, nb, default=4 * scfg.gamma * index.c)
     vals, idx = jax.lax.top_k(boundsum, budget)
     rank = jnp.arange(budget)[None, :]
     eligible = (vals > theta[:, None] / d.eta[:, None]) & (rank >= b0)
-    scores1, pos1 = _score_blocks_dispatch(index, qdense, idx, eligible, scfg, impl)
+    scores1, pos1 = _score_blocks_dispatch(index, qb_full, idx, eligible, scfg, impl)
 
     all_scores = jnp.concatenate([scores0, scores1], axis=1)
     all_pos = jnp.concatenate([pos0, pos1], axis=1)
@@ -350,29 +349,61 @@ def validate_dynamic(dyn: Dynamic, scfg: StaticConfig) -> None:
             p.validate_for(scfg)
 
 
-def make_dynamic_runner(fn, scfg: StaticConfig, defaults: DynamicParams, vocab: int, traces: dict):
-    """Wrap a jitted ``fn(tids, ws, k, mu, eta, beta)`` into the backend
-    contract every serving layer consumes: ``run(qb, dyn=None)`` with host-param
-    validation + [Q] broadcasting, ``run.warmup(shapes)`` sentinel
-    pre-compilation, ``run.n_traces()`` (the zero-recompilation counter), and
-    the ``supports_dynamic``/``static_cfg``/``defaults``/``vocab`` attributes.
+def split_arrays(tree):
+    """``(arrays, rebuild)``: the array leaves of an index pytree (an ``LSPIndex``,
+    a shard list, ...) and the function that puts arrays back around its static
+    leaves. The ints that fix shapes (b, c, vocab, n_blocks, bit widths, ...) stay
+    Python values; the arrays become arguments of the jitted program instead of
+    constants baked into it, so the program's size does not grow with the index
+    and one resident copy of the index serves every compiled bucket. Host
+    (numpy) arrays are put on the default device here, once, not on every call.
+    Anything with a ``shape`` and ``dtype`` counts as an array, so a tree of
+    ``jax.ShapeDtypeStruct`` lowers the same program without data."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    is_array = [hasattr(x, "shape") and hasattr(x, "dtype") for x in leaves]
+    arrays = [
+        jax.device_put(x) if isinstance(x, np.ndarray) else x
+        for x, a in zip(leaves, is_array)
+        if a
+    ]
+    statics = [x for x, a in zip(leaves, is_array) if not a]
+
+    def rebuild(arrs):
+        it_a, it_s = iter(arrs), iter(statics)
+        return treedef.unflatten([next(it_a) if a else next(it_s) for a in is_array])
+
+    return arrays, rebuild
+
+
+def make_dynamic_runner(
+    program, arrays, scfg: StaticConfig, defaults: DynamicParams, vocab: int, traces: dict
+):
+    """Wrap a jitted ``program(arrays, tids, ws, k, mu, eta, beta)`` into the
+    backend contract every serving layer consumes: ``run(qb, dyn=None)`` with
+    host-param validation + [Q] broadcasting, ``run.warmup(shapes)`` sentinel
+    pre-compilation, ``run.n_traces()`` (the zero-recompilation counter),
+    ``run.lower(tids, ws, k, mu, eta, beta)`` (the bucket's program, for
+    inspection or an AOT compile), and the
+    ``supports_dynamic``/``static_cfg``/``defaults``/``vocab`` attributes.
+    ``arrays`` (the index, see ``split_arrays``) ride every call as arguments.
     ``jit_search``, the 'exact' backend and ``ShardedRetriever`` all share THIS
     wrapper, so the contract cannot drift between backends."""
 
     def run(qb: QueryBatch, dyn: Dynamic = None):
         validate_dynamic(dyn, scfg)
         d = dynamic_args(defaults if dyn is None else dyn, qb.tids.shape[0], scfg.k_max)
-        return fn(qb.tids, qb.ws, d.k, d.mu, d.eta, d.beta)
+        return program(arrays, qb.tids, qb.ws, d.k, d.mu, d.eta, d.beta)
 
     def warmup(shapes) -> None:
         for q, nq in shapes:
             d = dynamic_args(defaults, q, scfg.k_max)
-            out = fn(
-                jnp.full((q, nq), vocab, jnp.int32), jnp.zeros((q, nq), jnp.float32), *d
+            out = program(
+                arrays, jnp.full((q, nq), vocab, jnp.int32), jnp.zeros((q, nq), jnp.float32), *d
             )
             jax.block_until_ready(out)
 
     run.warmup = warmup
+    run.lower = lambda *query: program.lower(arrays, *query)
     run.n_traces = lambda: traces["n"]
     run.supports_dynamic = True
     run.static_cfg = scfg
@@ -387,28 +418,31 @@ def jit_search(
     impl: str = "auto",
     defaults: Optional[DynamicParams] = None,
 ):
-    """Compile the dynamic traversal closed over the index: ONE XLA program per
+    """Compile the dynamic traversal over the index: ONE XLA program per
     (Q, nq) input shape serves ANY ``DynamicParams`` point — including mixed
     per-row points — with zero recompiles across a sweep.
 
-    The jit boundary takes (tids, ws) plus the four [Q] dynamic arrays; shapes
-    depend only on the batch, so a serving ladder's buckets each resolve to one
-    program through the returned callable. ``run.warmup(shapes)`` pre-triggers
-    those compilations, and ``run.n_traces()`` exposes the trace counter the
+    The jit boundary takes the index arrays (``split_arrays``: arguments, not
+    constants), (tids, ws) and the four [Q] dynamic arrays; shapes depend only
+    on the batch, so a serving ladder's buckets each resolve to one program
+    through the returned callable. ``run.warmup(shapes)`` pre-triggers those
+    compilations, and ``run.n_traces()`` exposes the trace counter the
     zero-recompilation property tests assert over.
     """
     vocab = index.vocab
     defaults = (defaults or DynamicParams(k=scfg.k_max)).validate_for(scfg)
     traces = {"n": 0}
+    arrays, rebuild = split_arrays(index)
 
     @jax.jit
-    def fn(tids, ws, k, mu, eta, beta):
+    def fn(arrays, tids, ws, k, mu, eta, beta):
         traces["n"] += 1  # python side effect: runs at trace time only
         return search_retrieve(
-            index, QueryBatch(tids, ws, vocab), scfg, DynamicArgs(k, mu, eta, beta), impl=impl
+            rebuild(arrays), QueryBatch(tids, ws, vocab), scfg, DynamicArgs(k, mu, eta, beta),
+            impl=impl,
         )
 
-    return make_dynamic_runner(fn, scfg, defaults, vocab, traces)
+    return make_dynamic_runner(fn, arrays, scfg, defaults, vocab, traces)
 
 
 # --------------------------------------------------------------- legacy shims
@@ -431,8 +465,9 @@ def retrieve(
 
 
 def jit_retrieve(index: LSPIndex, cfg: RetrievalConfig, impl: str = "auto"):
-    """Deprecated: compile a retriever closed over the index at one fixed
-    ``RetrievalConfig`` point. QueryBatch.vocab is static (shapes depend on it),
+    """Deprecated: compile a retriever over the index at one fixed
+    ``RetrievalConfig`` point (the index arrays ride as arguments, as in
+    ``jit_search``). QueryBatch.vocab is static (shapes depend on it),
     so the jit boundary takes only the tids/ws arrays; the dynamic parameters
     are baked into the trace as constants — this is exactly the "re-jitted
     static config" the dynamic path's bit-identity tests compare against.
@@ -450,18 +485,19 @@ def jit_retrieve(index: LSPIndex, cfg: RetrievalConfig, impl: str = "auto"):
     vocab = index.vocab
     scfg, dyn = cfg.split()
     traces = {"n": 0}
+    arrays, rebuild = split_arrays(index)
 
     @jax.jit
-    def fn(tids, ws):
+    def fn(arrays, tids, ws):
         traces["n"] += 1
-        return search_retrieve(index, QueryBatch(tids, ws, vocab), scfg, dyn, impl=impl)
+        return search_retrieve(rebuild(arrays), QueryBatch(tids, ws, vocab), scfg, dyn, impl=impl)
 
     def run(qb: QueryBatch):
-        return fn(qb.tids, qb.ws)
+        return fn(arrays, qb.tids, qb.ws)
 
     def warmup(shapes) -> None:
         for q, nq in shapes:
-            out = fn(jnp.full((q, nq), vocab, jnp.int32), jnp.zeros((q, nq), jnp.float32))
+            out = fn(arrays, jnp.full((q, nq), vocab, jnp.int32), jnp.zeros((q, nq), jnp.float32))
             jax.block_until_ready(out)
 
     run.warmup = warmup

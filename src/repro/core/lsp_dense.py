@@ -288,7 +288,7 @@ def make_sharded_dense_retriever(shards: list[DenseLSPIndex], cfg: RetrievalConf
     """shard_map dense LSP: each model-shard prunes + scores its candidate range with
     the full γ, then a hierarchical top-k merges (collectives O(P*k) instead of the
     pjit version's full candidate-array all-gather; see §Perf log)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     meta = shards[0]
@@ -305,7 +305,7 @@ def make_sharded_dense_retriever(shards: list[DenseLSPIndex], cfg: RetrievalConf
         mesh=mesh,
         in_specs=tuple([P("model", None, None)] * 5 + [P("model", None), P(None, None)]),
         out_specs=(P(None, None), P(None, None)),
-        check_rep=False,
+        check_vma=False,
     )
 
     def run(q):

@@ -25,7 +25,7 @@ from jax.sharding import PartitionSpec as P
 def vocab_parallel_lookup(table: jnp.ndarray, flat_ids: jnp.ndarray, mesh, batch_axes) -> jnp.ndarray:
     """table [R, D] (R divisible by model axis), flat_ids int32 [B, F] global row ids
     -> [B, F, D] replicated over model, sharded over batch axes."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n_model = mesh.shape["model"]
     r = table.shape[0]
@@ -46,7 +46,7 @@ def vocab_parallel_lookup(table: jnp.ndarray, flat_ids: jnp.ndarray, mesh, batch
         mesh=mesh,
         in_specs=(P("model", None), P(batch_axes, None)),
         out_specs=P(batch_axes, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(table, flat_ids)
 
@@ -66,7 +66,7 @@ def vocab_parallel_lookup_scattered(
     Requires B divisible by (batch shards x model). Output: [B/model_local, F, D]
     locally; global sharding P((batch_axes, 'model'), None, None).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     n_model = mesh.shape["model"]
     r = table.shape[0]
@@ -88,6 +88,6 @@ def vocab_parallel_lookup_scattered(
         mesh=mesh,
         in_specs=(P("model", None), P(batch_axes, None)),
         out_specs=P(out_batch, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(table, flat_ids)

@@ -27,7 +27,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.config import RetrievalConfig
 from repro.core.lsp import search_retrieve
@@ -111,6 +111,11 @@ def shard_index(index: LSPIndex, n_shards: int) -> list[LSPIndex]:
     return [_local_index(index, s, n_shards) for s in range(n_shards)]
 
 
+def to_host(shard: LSPIndex) -> LSPIndex:
+    """``shard`` with its device arrays copied to host memory (numpy)."""
+    return jax.tree.map(lambda x: np.asarray(x) if isinstance(x, jax.Array) else x, shard)
+
+
 def retrieve_distributed(
     shards: list[LSPIndex], qb: QueryBatch, cfg: RetrievalConfig, impl: str = "ref"
 ):
@@ -130,12 +135,17 @@ def retrieve_distributed(
 
 
 class StackedShards:
-    """Per-shard arrays stacked on a leading axis (shardable with P('model', ...))."""
+    """Per-shard arrays stacked on a leading axis, for a shard_map over ``mesh``.
+    Each stack is built on the host and placed once with
+    ``NamedSharding(mesh, P('model', ...))``: shard p lives only on the devices of
+    model index p, never gathered on one device first."""
 
-    def __init__(self, shards: list[LSPIndex]):
+    def __init__(self, shards: list[LSPIndex], mesh):
         self.meta = shards[0]
         self.n_shards = len(shards)
-        st = lambda get: jnp.stack([get(s) for s in shards])
+        self.shards = shards
+        self.mesh = mesh
+        st = self.stack
         self.sb_packed = st(lambda s: s.sb_bounds.packed)
         self.blk_packed = st(lambda s: s.blk_bounds.packed)
         self.fwdq_tids = st(lambda s: s.docs_fwdq.tids)
@@ -143,12 +153,18 @@ class StackedShards:
         self.fwdq_scales = st(lambda s: s.docs_fwdq.scales)
         self.remap = st(lambda s: s.doc_remap)
 
+    def stack(self, get):
+        """``get(shard)`` of every shard, stacked on a new leading (shard) axis."""
+        host = np.stack([np.asarray(get(s)) for s in self.shards])
+        spec = P("model", *([None] * (host.ndim - 1)))
+        return jax.device_put(host, NamedSharding(self.mesh, spec))
+
 
 def make_mesh_retriever(shards: list[LSPIndex], cfg: RetrievalConfig, mesh, impl: str = "auto"):
     """shard_map retriever: index shards over `model`, queries over pod/data axes."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
-    stacked = StackedShards(shards)
+    stacked = StackedShards(shards, mesh)
     meta = stacked.meta
     batch_axes = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
 
@@ -196,19 +212,19 @@ def make_mesh_retriever(shards: list[LSPIndex], cfg: RetrievalConfig, mesh, impl
             qspec,
         ),
         out_specs=(qspec, qspec),
-        check_rep=False,
+        check_vma=False,
+    )
+    jfn = jax.jit(fn)
+    arrays = (
+        stacked.sb_packed,
+        stacked.blk_packed,
+        stacked.fwdq_tids,
+        stacked.fwdq_ws,
+        stacked.fwdq_scales,
+        stacked.remap,
     )
 
     def run(qb: QueryBatch):
-        return fn(
-            stacked.sb_packed,
-            stacked.blk_packed,
-            stacked.fwdq_tids,
-            stacked.fwdq_ws,
-            stacked.fwdq_scales,
-            stacked.remap,
-            qb.tids,
-            qb.ws,
-        )
+        return jfn(*arrays, qb.tids, qb.ws)
 
-    return jax.jit(run), stacked
+    return run, stacked

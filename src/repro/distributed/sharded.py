@@ -65,7 +65,7 @@ from typing import NamedTuple, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import ops
 from repro.core.config import (
@@ -82,12 +82,13 @@ from repro.core.lsp import (
     mask_beyond_k,
     masked_kth_min,
     resolve_block_budget,
+    split_arrays,
 )
-from repro.core.query import QueryBatch, prune_terms, scatter_dense
+from repro.core.query import QueryBatch, prune_terms
 from repro.core.scoring import NEG, score_blocks
 from repro.core.topk import canonical_keep_mask, canonical_topk
 from repro.index.layout import LSPIndex
-from repro.distributed.retrieval import StackedShards, shard_index
+from repro.distributed.retrieval import StackedShards, shard_index, to_host
 
 
 class ShardedRetrievalResult(NamedTuple):
@@ -163,14 +164,14 @@ def _phase1_local(local: LSPIndex, qb_pr: QueryBatch, impl: str, plan: _Plan):
     return jax.lax.top_k(sbmax_l, plan.budget_l)
 
 
-def _round0_local(local: LSPIndex, qdense, g_ids, lo, scfg, impl, plan: _Plan):
+def _round0_local(local: LSPIndex, qb_full, g_ids, lo, scfg, impl, plan: _Plan):
     """Score the shard's members of the GLOBAL top-γ₀ superblocks."""
     g0_ids = g_ids[:, : plan.g0]
     owned0 = (g0_ids >= lo) & (g0_ids < lo + plan.ns_l)
     loc0 = jnp.clip(g0_ids - lo, 0, plan.ns_l - 1)
     blk0 = _expand_superblocks(loc0, local.c)  # [Q, g0*c] local block ids
     mask0 = jnp.repeat(owned0, local.c, axis=1)
-    scores0, pos0 = score_blocks(local, qdense, blk0, mask0, scfg.doc_layout, impl)
+    scores0, pos0 = score_blocks(local, qb_full, blk0, mask0, scfg.doc_layout, impl)
     return owned0, loc0, scores0, pos0
 
 
@@ -286,7 +287,7 @@ def merge_block_cutoff(cat_vals, cat_gids, plan: _Plan):
 def _phase3_local(
     local: LSPIndex,
     lo,
-    qdense,
+    qb_full,
     p2: _Phase2,
     owned0,
     loc0,
@@ -322,7 +323,7 @@ def _phase3_local(
         blk_mask = lb_mask & canonical_keep_mask(lb_vals, lb_gids, cut_v, cut_id)
         blk_ids = jnp.where(blk_mask, lb_gids - lo * c, 0)  # local block ids
 
-    scores1, pos1 = score_blocks(local, qdense, blk_ids, blk_mask, scfg.doc_layout, impl)
+    scores1, pos1 = score_blocks(local, qb_full, blk_ids, blk_mask, scfg.doc_layout, impl)
 
     all_scores = jnp.concatenate([scores0, scores1], axis=1)
     all_pos = jnp.concatenate([pos0, pos1], axis=1)
@@ -391,7 +392,6 @@ def sharded_retrieve(
     d = dynamic_args(dyn, qb_full.tids.shape[0], scfg.k_max)
     bounds_impl = impl
     qb_pr = prune_terms(qb_full, d.beta)
-    qdense = scatter_dense(qb_full)
 
     # stage 1: local candidates -> global canonical candidate list (replicated)
     lvs, lis = zip(*(_phase1_local(s, qb_pr, bounds_impl, plan) for s in shards))
@@ -405,7 +405,7 @@ def sharded_retrieve(
 
     # stage 2: round-0 scoring of owned global-top-γ₀ members -> global θ
     r0 = [
-        _round0_local(s, qdense, g_ids, p * plan.ns_l, scfg, impl, plan)
+        _round0_local(s, qb_full, g_ids, p * plan.ns_l, scfg, impl, plan)
         for p, s in enumerate(shards)
     ]
     shard_theta = jnp.stack(
@@ -434,7 +434,7 @@ def sharded_retrieve(
     # phase 3: block selection + scoring, local canonical top-k
     parts = [
         _phase3_local(
-            s, p * plan.ns_l, qdense, p2s[p],
+            s, p * plan.ns_l, qb_full, p2s[p],
             r0[p][0], r0[p][1], r0[p][2], r0[p][3], cuts[p], scfg, d, impl, plan,
         )
         for p, s in enumerate(shards)
@@ -462,7 +462,10 @@ def sharded_retrieve(
 # ------------------------------------------------------------------- shard_map
 
 
-def _local_index_from(meta: LSPIndex, sb_packed, blk_packed, sbavg_packed, tids, ws, scales, remap) -> LSPIndex:
+def _local_index_from(
+    meta: LSPIndex, sb_packed, blk_packed, sbavg_packed, tids, ws, scales, remap, bound_scales
+) -> LSPIndex:
+    sb_scale, blk_scale, *avg_scale = bound_scales
     return LSPIndex(
         b=meta.b,
         c=meta.c,
@@ -470,9 +473,11 @@ def _local_index_from(meta: LSPIndex, sb_packed, blk_packed, sbavg_packed, tids,
         vocab=meta.vocab,
         n_blocks=meta.n_blocks,
         n_superblocks=meta.n_superblocks,
-        sb_bounds=meta.sb_bounds._replace(packed=sb_packed),
-        blk_bounds=meta.blk_bounds._replace(packed=blk_packed),
-        sb_avg=None if meta.sb_avg is None else meta.sb_avg._replace(packed=sbavg_packed),
+        sb_bounds=meta.sb_bounds._replace(packed=sb_packed, scale=sb_scale),
+        blk_bounds=meta.blk_bounds._replace(packed=blk_packed, scale=blk_scale),
+        sb_avg=None
+        if meta.sb_avg is None
+        else meta.sb_avg._replace(packed=sbavg_packed, scale=avg_scale[0]),
         docs_fwd=None,
         docs_flat=None,
         doc_remap=remap,
@@ -482,14 +487,20 @@ def _local_index_from(meta: LSPIndex, sb_packed, blk_packed, sbavg_packed, tids,
 
 
 class _StackedShardsAvg(StackedShards):
-    """StackedShards + the sb_avg operand (needed by lsp2/sp under sharding)."""
+    """StackedShards + the sb_avg operand (needed by lsp2/sp under sharding) + the
+    per-term bound scales every shard shares, replicated over the mesh."""
 
-    def __init__(self, shards: Sequence[LSPIndex]):
-        super().__init__(list(shards))
+    def __init__(self, shards: Sequence[LSPIndex], mesh):
+        super().__init__(list(shards), mesh)
+        meta = shards[0]
         self.sbavg_packed = (
-            None
-            if shards[0].sb_avg is None
-            else jnp.stack([s.sb_avg.packed for s in shards])
+            None if meta.sb_avg is None else self.stack(lambda s: s.sb_avg.packed)
+        )
+        replicated = NamedSharding(mesh, P())
+        self.bound_scales = tuple(
+            jax.device_put(np.asarray(pb.scale, np.float32), replicated)
+            for pb in (meta.sb_bounds, meta.blk_bounds, meta.sb_avg)
+            if pb is not None
         )
 
 
@@ -497,11 +508,13 @@ def make_sharded_mesh_fn(
     shards: Sequence[LSPIndex], scfg: StaticConfig, mesh, impl: str, ns_true: int
 ):
     """shard_map transport: same stages, lax.all_gather merges over `model`.
-    The returned fn takes (tids, ws, k, mu, eta, beta) — the dynamic point rides
-    the same replicated (or data-sharded) spec as the query batch."""
-    from jax.experimental.shard_map import shard_map
+    Returns ``(fn, arrays)``: ``arrays`` are the stacked shard operands, placed
+    once over the mesh's ``model`` axis, and ``fn(arrays, tids, ws, k, mu, eta,
+    beta)`` takes them as arguments — the dynamic point rides the same
+    replicated (or data-sharded) spec as the query batch."""
+    from jax import shard_map
 
-    stacked = _StackedShardsAvg(shards)
+    stacked = _StackedShardsAvg(shards, mesh)
     meta = stacked.meta
     plan = make_plan(scfg, ns_true, meta.n_superblocks, meta.c, meta.b, len(shards))
     batch_axes = ("pod", "data") if "pod" in mesh.axis_names else ("data",)
@@ -509,16 +522,15 @@ def make_sharded_mesh_fn(
     qspec = P(batch_axes, None) if data_sharded else P(None, None)
     have_avg = stacked.sbavg_packed is not None
 
-    def local_fn(sb_packed, blk_packed, sbavg_packed, fwdq_tids, fwdq_ws, fwdq_scales, remap, q_tids, q_ws, d_k, d_mu, d_eta, d_beta):
+    def local_fn(sb_packed, blk_packed, sbavg_packed, fwdq_tids, fwdq_ws, fwdq_scales, remap, bound_scales, q_tids, q_ws, d_k, d_mu, d_eta, d_beta):
         local = _local_index_from(
             meta, sb_packed[0], blk_packed[0], None if not have_avg else sbavg_packed[0],
-            fwdq_tids[0], fwdq_ws[0], fwdq_scales[0], remap[0],
+            fwdq_tids[0], fwdq_ws[0], fwdq_scales[0], remap[0], bound_scales,
         )
         lo = jax.lax.axis_index("model") * plan.ns_l
         qb = QueryBatch(q_tids, q_ws, meta.vocab)
         d = DynamicArgs(d_k, d_mu, d_eta, d_beta)
         qb_pr = prune_terms(qb, d.beta)
-        qdense = scatter_dense(qb)
 
         lv, li = _phase1_local(local, qb_pr, impl, plan)
         vals_cat = jax.lax.all_gather(lv, "model", axis=1, tiled=True)
@@ -527,7 +539,7 @@ def make_sharded_mesh_fn(
             vals_cat, ids_cat, plan.budget, id_bound=plan.ns_l * plan.n_shards
         )
 
-        owned0, loc0, scores0, pos0 = _round0_local(local, qdense, g_ids, lo, scfg, impl, plan)
+        owned0, loc0, scores0, pos0 = _round0_local(local, qb, g_ids, lo, scfg, impl, plan)
         theta_l = _local_theta(scores0, plan, d.k)
         th_lists = jax.lax.all_gather(
             jax.lax.top_k(scores0, plan.k_l)[0], "model", axis=1, tiled=True
@@ -546,7 +558,7 @@ def make_sharded_mesh_fn(
             cut_v, cut_id = merge_block_cutoff(cat_v, cat_g, plan)
             cut = (lb_vals, lb_gids, lb_mask, cut_v, cut_id)
         ids_k, vals_k, n_sb, n_blk, n_cand = _phase3_local(
-            local, lo, qdense, p2, owned0, loc0, scores0, pos0, cut, scfg, d, impl, plan,
+            local, lo, qb, p2, owned0, loc0, scores0, pos0, cut, scfg, d, impl, plan,
         )
         fids = jax.lax.all_gather(ids_k, "model", axis=1, tiled=True)
         fvals = jax.lax.all_gather(vals_k, "model", axis=1, tiled=True)
@@ -581,6 +593,7 @@ def make_sharded_mesh_fn(
             P("model", None, None, None),
             P("model", None),
             P("model", None),
+            tuple(P() for _ in stacked.bound_scales),
             qspec,
             qspec,
             vec_spec,
@@ -599,28 +612,23 @@ def make_sharded_mesh_fn(
             shard_blocks=qspec,
             shard_candidates=qspec,
         ),
-        check_rep=False,
+        check_vma=False,
     )
-    dummy_avg = jnp.zeros((1,), jnp.uint32)
+    arrays = (
+        stacked.sb_packed,
+        stacked.blk_packed,
+        stacked.sbavg_packed if have_avg else jnp.zeros((1,), jnp.uint32),
+        stacked.fwdq_tids,
+        stacked.fwdq_ws,
+        stacked.fwdq_scales,
+        stacked.remap,
+        stacked.bound_scales,
+    )
 
-    def run(tids, ws, k, mu, eta, beta):
-        return fn(
-            stacked.sb_packed,
-            stacked.blk_packed,
-            stacked.sbavg_packed if have_avg else dummy_avg,
-            stacked.fwdq_tids,
-            stacked.fwdq_ws,
-            stacked.fwdq_scales,
-            stacked.remap,
-            tids,
-            ws,
-            k,
-            mu,
-            eta,
-            beta,
-        )
+    def run(arrays, tids, ws, k, mu, eta, beta):
+        return fn(*arrays, tids, ws, k, mu, eta, beta)
 
-    return run
+    return run, arrays
 
 
 # ------------------------------------------------------------------- retriever
@@ -681,20 +689,25 @@ class ShardedRetriever:
             assert mesh.shape["model"] == self.n_shards, (
                 f"mesh model axis {mesh.shape['model']} != n_shards {self.n_shards}"
             )
-            mesh_run = make_sharded_mesh_fn(shards, scfg, mesh, impl, ns_true)
+            # the mesh transport places each shard on its own devices
+            # (StackedShards); the shards keep no copy on the default device
+            shards = self.shards = [to_host(s) for s in shards]
+            mesh_run, arrays = make_sharded_mesh_fn(shards, scfg, mesh, impl, ns_true)
 
             @jax.jit
-            def _fn(tids, ws, k, mu, eta, beta):
+            def _fn(arrays, tids, ws, k, mu, eta, beta):
                 traces["n"] += 1
-                return mesh_run(tids, ws, k, mu, eta, beta)
+                return mesh_run(arrays, tids, ws, k, mu, eta, beta)
 
             self._fn = _fn
         else:
-            sh, imp, nst = shards, impl, ns_true
+            imp, nst = impl, ns_true
+            arrays, rebuild = split_arrays(shards)
 
             @jax.jit
-            def _host(tids, ws, k, mu, eta, beta):
+            def _host(arrays, tids, ws, k, mu, eta, beta):
                 traces["n"] += 1
+                sh = rebuild(arrays)
                 return sharded_retrieve(
                     sh, QueryBatch(tids, ws, sh[0].vocab), scfg, imp, nst,
                     dyn=DynamicArgs(k, mu, eta, beta),
@@ -703,7 +716,9 @@ class ShardedRetriever:
             self._fn = _host
         # the same wrapper jit_search and the 'exact' backend use: validation,
         # [Q] broadcasting, sentinel warmup, trace counter — one contract
-        self._run = make_dynamic_runner(self._fn, scfg, self.defaults, self.vocab, traces)
+        self._run = make_dynamic_runner(
+            self._fn, arrays, scfg, self.defaults, self.vocab, traces
+        )
 
     def __call__(self, qb: QueryBatch, dyn=None) -> ShardedRetrievalResult:
         return self._run(qb, dyn)

@@ -34,7 +34,8 @@ class IndexBuildConfig:
     build_flat_inv: bool = True
     build_avg: bool = True  # superblock averages (needed by SP and LSP/2 only)
     # lane alignment of the quantized scoring operands (FwdDocsQ.t_pad / FlatDocsQ.m).
-    # 8 keeps host gathers compact (CPU ref path); set 128 for full TPU lane tiles.
+    # 8 keeps host gathers compact (CPU ref path); 128 gives the TPU kernels full lane
+    # tiles (configs.lsp_msmarco's deployment configs set it).
     lane_pad: int = 8
     d_proj: int = 64
     kmeans_iters: int = 8

@@ -10,13 +10,8 @@
                   (phase-3 hot path; quantized forward index, VPU accumulate)
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit wrapper),
-ref.py (pure-jnp oracle). Validated on CPU with interpret=True.
+ref.py (pure-jnp oracle). Off the TPU the tests run them with interpret=True against
+the oracles; tests/test_chip_compile.py compiles the retrieval kernels for a described
+TPU v5e at real widths, and chip_smoke.py runs them compiled on the chip.
 """
 
-from __future__ import annotations
-
-from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both so kernels run on
-# every toolchain in the container fleet.
-tpu_compiler_params = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams

@@ -4,9 +4,11 @@ out[q, s, b] = sum_i ws[q, i] * unpack(packed3[tids[q, i], sel[q, s], :])[b]
 
 packed3 is the block-level max-weight matrix viewed [V, NS, cw]: superblock granules of
 cw = c*bits/32 words, the word-aligned random-access unit that the paper's
-selectors-first SIMDBP-256* layout provides on CPU. Each grid step DMAs exactly one
-(term row x superblock granule) — a small load by design: two-level pruning is *about*
-touching only the selected superblocks' block metadata. The DMA pipeline hides the
+selectors-first SIMDBP-256* layout provides on CPU. A TPU block must be
+(8, 128)-aligned, so each grid step DMAs the aligned (ROWS, L) word tile that holds the
+granule (L = 128 lanes, or the whole row when it is narrower or not lane-aligned),
+picks the term's row in-register and accumulates its unpacked (vpw, L) tile. The
+wrapper then reads the granule's cw lanes out of that tile. The DMA pipeline hides the
 latency across the (Q, S, nq) grid; Q and S are parallel dims.
 """
 
@@ -19,13 +21,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+from repro.kernels.sbmax.kernel import ROWS, unpack_tile_row
+
+LANES = 128
 
 
-def _kernel(tids_ref, ws_ref, sel_ref, packed_ref, out_ref, *, bits: int, cw: int):
+def _kernel(tids_ref, ws_ref, sel_ref, packed_ref, out_ref, *, bits: int):
     q = pl.program_id(0)
     i = pl.program_id(2)
-    vpw = 32 // bits
 
     @pl.when(i == 0)
     def _init():
@@ -35,10 +38,7 @@ def _kernel(tids_ref, ws_ref, sel_ref, packed_ref, out_ref, *, bits: int, cw: in
 
     @pl.when(w != 0.0)
     def _acc():
-        gran = packed_ref[0, 0, :]  # [cw] uint32
-        shifts = jax.lax.broadcasted_iota(jnp.uint32, (vpw, cw), 0) * bits
-        mask = jnp.uint32((1 << bits) - 1)
-        vals = (gran[None, :] >> shifts) & mask  # [vpw, cw] -> value order j*cw + w'
+        vals = unpack_tile_row(packed_ref[...], tids_ref[q, i] % ROWS, bits)
         out_ref[0, 0] += w * vals.astype(jnp.float32)
 
 
@@ -54,32 +54,36 @@ def boundsum_gather_pallas(
     """Returns float32 [Q, S, c] unscaled block bound sums."""
     cw = c * bits // 32
     vpw = 32 // bits
-    v = packed.shape[0]
-    packed3 = packed.reshape(v, -1, cw)
+    w_words = packed.shape[1]
+    lanes = LANES if w_words % LANES == 0 else w_words
+    assert lanes % cw == 0, f"granule {cw} words straddles a {lanes}-word tile"
     q, nq = tids.shape
     s = sel_sb.shape[1]
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, bits=bits, cw=cw),
+    tiles = pl.pallas_call(
+        functools.partial(_kernel, bits=bits),
+        name="boundsum_gather",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(q, s, nq),
             in_specs=[
                 pl.BlockSpec(
-                    (1, 1, cw),
+                    (ROWS, lanes),
                     lambda qi, si, i, tids_ref, ws_ref, sel_ref: (
-                        tids_ref[qi, i],
-                        sel_ref[qi, si],
-                        0,
+                        tids_ref[qi, i] // ROWS,
+                        sel_ref[qi, si] * cw // lanes,
                     ),
                 ),
             ],
-            out_specs=pl.BlockSpec((1, 1, vpw, cw), lambda qi, si, i, *_: (qi, si, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, vpw, lanes), lambda qi, si, i, *_: (qi, si, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((q, s, vpw, cw), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        out_shape=jax.ShapeDtypeStruct((q, s, vpw, lanes), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(tids, ws, sel_sb, packed3)
-    return out.reshape(q, s, vpw * cw)
+    )(tids, ws, sel_sb, packed)
+    # the granule's cw lanes of each tile, in value order j*cw + w'
+    lane = (sel_sb * cw % lanes)[:, :, None, None] + jnp.arange(cw)[None, None, None, :]
+    gran = jnp.take_along_axis(tiles, jnp.broadcast_to(lane, (q, s, vpw, cw)), axis=3)
+    return gran.reshape(q, s, vpw * cw)

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 TW = 128  # lane width of a packed word tile (== pack.SEG_WORDS)
 
 
@@ -71,7 +69,7 @@ def dequant_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((tm, 1, vpw, TW), lambda mi, s, ki: (mi, s, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n_seg, vpw, TW), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -55,6 +55,7 @@ from repro.index.store import (
     save_index,
     save_sharded_index,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import AdmissionConfig, DeadlineExceeded, SLOConfig, TenantQuota
 
 
@@ -111,6 +112,7 @@ def main() -> None:
                    help="admission quotas 'tenant=rate[/burst],...' in requests/s; "
                         "tenant 'default' covers unlisted tenants")
     args = p.parse_args()
+    print(f"[serve] compile cache {enable_compile_cache()}")
 
     ccfg = CorpusConfig(n_docs=args.n_docs, vocab=args.vocab, n_topics=32, seed=0)
     corpus = make_corpus(ccfg)

@@ -518,7 +518,7 @@ def _recsys_retrieval_cell(arch: ArchConfig, shape: ShapeSpec, mesh, params_s, p
     baxes = _batch_axes(mesh)
 
     if arch.name == "mind":
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from repro.core.config import RetrievalConfig
         from repro.core.lsp_dense import DenseLSPIndex, PackedMinMax, dense_local_fn
@@ -549,7 +549,7 @@ def _recsys_retrieval_cell(arch: ArchConfig, shape: ShapeSpec, mesh, params_s, p
             mesh=mesh,
             in_specs=tuple([P("model", None, None)] * 5 + [P("model", None), P(None, None)]),
             out_specs=(P(None, None), P(None, None)),
-            check_rep=False,
+            check_vma=False,
         )
 
         args = (

@@ -120,7 +120,7 @@ def test_distributed_topk():
         """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.distributed.topk import distributed_topk
         from repro.launch.mesh import make_host_mesh
         mesh = make_host_mesh(model=4, data=1)
@@ -129,7 +129,7 @@ def test_distributed_topk():
         def f(s):
             return distributed_topk(s, 5, "model")
         fn = shard_map(f, mesh=mesh, in_specs=(P(None, "model"),),
-                       out_specs=(P(None, None), P(None, None)), check_rep=False)
+                       out_specs=(P(None, None), P(None, None)), check_vma=False)
         vals, ids = fn(scores)
         ref_vals, ref_ids = jax.lax.top_k(scores, 5)
         np.testing.assert_allclose(np.asarray(vals), np.asarray(ref_vals), rtol=1e-6)
@@ -146,7 +146,7 @@ def test_grad_compression_error_feedback():
         """
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.optim.grad_compress import compressed_psum, init_error_feedback
         from repro.launch.mesh import make_host_mesh
         mesh = make_host_mesh(model=1, data=4)
@@ -157,7 +157,7 @@ def test_grad_compression_error_feedback():
             out, ef = compressed_psum({"g": g[0]}, ef, "data")
             return out["g"][None], ef.err["g"][None]
         fn = shard_map(f, mesh=mesh, in_specs=(P("data", None),),
-                       out_specs=(P("data", None), P("data", None)), check_rep=False)
+                       out_specs=(P("data", None), P("data", None)), check_vma=False)
         mean_c, err = fn(g_local)
         true_mean = np.asarray(g_local).mean(axis=0)
         got = np.asarray(mean_c)[0]

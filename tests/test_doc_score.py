@@ -32,17 +32,24 @@ def _qdense(rng, q, vocab):
     return jnp.asarray(qd)
 
 
+def _dense_terms(qdense):
+    """A dense query row as the kernels' (term id, weight) lists: every id once."""
+    q, vp = qdense.shape
+    return jnp.broadcast_to(jnp.arange(vp, dtype=jnp.int32), (q, vp)), qdense
+
+
 @pytest.mark.parametrize("nb,b,t,vocab,q,s", [(32, 8, 16, 64, 2, 5), (17, 4, 24, 300, 3, 9), (8, 16, 8, 33, 1, 3)])
 def test_doc_score_fwd_matches_ref(nb, b, t, vocab, q, s):
     rng = np.random.default_rng(nb * 10 + b)
     fwdq = _rand_fwdq(rng, nb, b, t, vocab)
     qdense = _qdense(rng, q, vocab)
     blk = jnp.asarray(rng.integers(0, nb, (q, s)).astype(np.int32))
-    out_k = doc_score_fwd_pallas(fwdq.tids, fwdq.ws, qdense, blk, interpret=True)
+    q_tids, q_ws = _dense_terms(qdense)
+    out_k = doc_score_fwd_pallas(fwdq.tids, fwdq.ws, q_tids, q_ws, blk, interpret=True)
     out_r = doc_score_fwd_ref(fwdq, qdense, blk)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), rtol=1e-5, atol=1e-4)
     # op wrapper applies per-block scales on both paths identically
-    scaled = doc_score_fwd_op(fwdq, qdense, blk, interpret=True)
+    scaled = doc_score_fwd_op(fwdq, q_tids, q_ws, blk, interpret=True)
     np.testing.assert_allclose(
         np.asarray(scaled),
         np.asarray(out_r) * np.asarray(fwdq.scales)[np.asarray(blk)][:, :, None],
@@ -66,10 +73,13 @@ def test_doc_score_flat_matches_ref(nb, b, m, vocab, q, s):
     flatq = FlatDocsQ(jnp.asarray(tids), jnp.asarray(ws), jnp.asarray(doc_ends), jnp.asarray(scales), 8, m)
     qdense = _qdense(rng, q, vocab)
     blk = jnp.asarray(rng.integers(0, nb, (q, s)).astype(np.int32))
-    out_k = doc_score_flat_pallas(flatq.tids, flatq.ws, flatq.doc_ends, qdense, blk, interpret=True)
+    q_tids, q_ws = _dense_terms(qdense)
+    out_k = doc_score_flat_pallas(
+        flatq.tids, flatq.ws, flatq.doc_ends, q_tids, q_ws, blk, interpret=True
+    )
     out_r = doc_score_flat_ref(flatq, qdense, blk)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r), rtol=1e-5, atol=1e-4)
-    scaled = doc_score_flat_op(flatq, qdense, blk, interpret=True)
+    scaled = doc_score_flat_op(flatq, q_tids, q_ws, blk, interpret=True)
     np.testing.assert_allclose(
         np.asarray(scaled),
         np.asarray(out_r) * scales[np.asarray(blk)][:, :, None],
@@ -80,14 +90,11 @@ def test_doc_score_flat_matches_ref(nb, b, m, vocab, q, s):
 def test_doc_score_layouts_agree(tiny_index, tiny_qb):
     """fwd and flat quantized operands hold the same per-block-quantized weights, so
     raw per-doc scores must agree exactly across layouts (ref and kernel)."""
-    from repro.core.query import scatter_dense
-
     rng = np.random.default_rng(0)
-    qdense = scatter_dense(tiny_qb)
-    q = qdense.shape[0]
+    q = tiny_qb.tids.shape[0]
     blk = jnp.asarray(rng.integers(0, tiny_index.n_blocks, (q, 12)).astype(np.int32))
-    fwd = doc_score_fwd_op(tiny_index.docs_fwdq, qdense, blk, interpret=True)
-    flat = doc_score_flat_op(tiny_index.docs_flatq, qdense, blk, interpret=True)
+    fwd = doc_score_fwd_op(tiny_index.docs_fwdq, tiny_qb.tids, tiny_qb.ws, blk, interpret=True)
+    flat = doc_score_flat_op(tiny_index.docs_flatq, tiny_qb.tids, tiny_qb.ws, blk, interpret=True)
     np.testing.assert_allclose(np.asarray(fwd), np.asarray(flat), rtol=1e-5, atol=1e-4)
 
 
@@ -108,10 +115,28 @@ def test_retrieve_kernel_matches_ref(tiny_index, tiny_qb, layout):
 def test_doc_score_sentinel_blocks_clamped(tiny_index, tiny_qb):
     """Out-of-range block ids (padding) are clamped, never out-of-bounds; the caller's
     mask is what excludes them — scores at clamped ids are finite."""
-    from repro.core.query import scatter_dense
-
-    qdense = scatter_dense(tiny_qb)
-    q = qdense.shape[0]
+    q = tiny_qb.tids.shape[0]
     blk = jnp.full((q, 4), tiny_index.n_blocks + 99, jnp.int32)
-    out = doc_score_fwd_op(tiny_index.docs_fwdq, qdense, blk, interpret=True)
+    out = doc_score_fwd_op(tiny_index.docs_fwdq, tiny_qb.tids, tiny_qb.ws, blk, interpret=True)
     assert np.isfinite(np.asarray(out)).all()
+
+
+@pytest.mark.parametrize("layout", ["fwd", "flat"])
+def test_doc_score_query_groups_match_one_call(tiny_index, tiny_qb, layout):
+    """A batch whose block ids exceed the SMEM budget runs as calls over query
+    groups (here 3 rows each, the last group padded); the result is the one-call one."""
+    from repro.kernels.doc_score.ops import per_query_groups
+
+    rng = np.random.default_rng(1)
+    q = tiny_qb.tids.shape[0]
+    blk = jnp.asarray(rng.integers(0, tiny_index.n_blocks, (q, 12)).astype(np.int32))
+    if layout == "flat":
+        fq = tiny_index.docs_flatq
+        kernel, operands = doc_score_flat_pallas, (fq.tids, fq.ws, fq.doc_ends)
+    else:
+        fq = tiny_index.docs_fwdq
+        kernel, operands = doc_score_fwd_pallas, (fq.tids, fq.ws)
+    args = (kernel, operands, tiny_qb.tids, tiny_qb.ws, blk, True)
+    grouped = per_query_groups(*args, budget=3 * 12)
+    assert q % 3 != 0  # the padded last group is exercised
+    np.testing.assert_array_equal(np.asarray(grouped), np.asarray(per_query_groups(*args)))

@@ -132,3 +132,40 @@ def test_fwd_index_roundtrip(tiny_corpus, tiny_index):
         assert set(got) == set(true)
         for t, w in got.items():
             assert abs(w * idx.docs_fwd.scale - true[t]) <= idx.docs_fwd.scale / 2 + 1e-6
+
+
+def test_project_docs_sums_in_posting_order(tiny_corpus):
+    """The projection equals the one-posting-at-a-time scatter (same arithmetic,
+    same order), empty documents included."""
+    from repro.index.clustering import project_docs
+
+    _, corpus, _ = tiny_corpus
+    lens = np.diff(corpus.doc_ptr)
+    lens[3] = 0  # an empty document keeps a zero row
+    doc_ptr = np.concatenate([[0], np.cumsum(lens)])
+    tids, ws = corpus.tids[: doc_ptr[-1]], corpus.ws[: doc_ptr[-1]]
+    d_proj, seed = 16, 5
+    rng = np.random.default_rng(seed)
+    proj = rng.standard_normal((corpus.vocab, d_proj), dtype=np.float32) / np.sqrt(d_proj)
+    want = np.zeros((len(lens), d_proj), np.float32)
+    np.add.at(want, np.repeat(np.arange(len(lens)), lens), ws[:, None] * proj[tids])
+    want /= np.maximum(np.linalg.norm(want, axis=1, keepdims=True), 1e-9)
+    got = project_docs(doc_ptr, tids, ws, corpus.vocab, d_proj, seed)
+    np.testing.assert_array_equal(got, want)
+    assert not got[3].any()
+
+
+def test_kmeans_over_document_chunks_matches_one_chunk(monkeypatch):
+    """The assignment step over padded document chunks (bounded memory) finds the
+    same clusters as over the whole [n, k] matrix; only the float order of the
+    centroid sums differs."""
+    from repro.index import clustering
+
+    rng = np.random.default_rng(0)
+    centers = rng.standard_normal((8, 16)).astype(np.float32) * 4
+    x = (centers[rng.integers(0, 8, 400)] + rng.standard_normal((400, 16))).astype(np.float32)
+    whole_a, whole_c = clustering.kmeans(x, 8, iters=4, seed=0)
+    monkeypatch.setattr(clustering, "CHUNK_ELEMS", 8 * 64)  # 64 rows a chunk: 7, padded
+    chunk_a, chunk_c = clustering.kmeans(x, 8, iters=4, seed=0)
+    np.testing.assert_array_equal(chunk_a, whole_a)
+    np.testing.assert_allclose(chunk_c, whole_c, rtol=1e-5, atol=1e-5)

@@ -45,6 +45,7 @@ _JIT_WRAPPERS = {
     "jax.pmap",
     "jax.vmap",
     "shard_map",
+    "jax.shard_map",
     "jax.experimental.shard_map.shard_map",
     "pl.pallas_call",
     "pallas_call",
