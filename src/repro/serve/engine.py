@@ -56,6 +56,16 @@ End-to-end latency percentiles (the paper's MRT metric at serving level) cover
 own counters and never enter the latency window, so a rejection-heavy burst
 cannot make p50/p99 look better. Queue-depth and SLO-level gauges ride
 ``ServeStats.summary()``.
+
+Tracing: every stage is a ``jax.profiler.TraceAnnotation`` span, on the clock of
+the device trace and recorded only while a profiler trace runs. On the caller's
+thread ``serve.admit`` (``request_id``), and inside it ``serve.backpressure``
+while a full lane holds the caller. On the worker ``serve.collect`` (waiting for
+the first request, then the batching window) and ``serve.batch`` (``batch_id``,
+``bucket``, ``n``, ``request_ids``), which its four stages cover: ``serve.pad``,
+``serve.dispatch`` (the retriever call, compiles included), ``serve.fetch`` (the
+outputs copied to the host) and ``serve.resolve`` (records, cache fills, futures,
+stats).
 """
 
 from __future__ import annotations
@@ -72,6 +82,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.api.types import SearchRequest, SearchResponse
 from repro.core.config import DynamicParams
@@ -111,6 +122,9 @@ class ServeStats:
       rejected          shed at shutdown (EngineShutdown) or post-stop submit
       degraded          subset of ``requests`` served below the requested point
 
+    ``queue_wait_ms_total`` / ``queue_waits``: the time from admission to the start
+    of its batch's dispatch, summed over the requests scored on the device.
+
     Gauges (live callables registered by the engine, evaluated at summary()
     time): ``queue_depth``, ``slo_level``."""
 
@@ -136,6 +150,8 @@ class ServeStats:
     # MutableRetrieverAdapter): each may come up short of k until compaction —
     # a freshness hazard, gated to zero in benchmarks.freshness_suite
     overfetch_saturated: int = 0
+    queue_wait_ms_total: float = 0.0
+    queue_waits: int = 0
     bucket_batches: dict = field(default_factory=dict)  # (batch, nq) -> count
 
     def __post_init__(self):
@@ -148,7 +164,8 @@ class ServeStats:
         """Expose a live reading (queue depth, SLO level, ...) in summary()."""
         self._gauges[name] = fn
 
-    def record(self, latency_ms: float, cache_hit: bool = False, degraded: bool = False) -> None:
+    def record(self, latency_ms: float, cache_hit: bool = False, degraded: bool = False,
+               queue_wait_ms: Optional[float] = None) -> None:
         with self._lock:
             self.latencies_ms.append(latency_ms)
             self.requests += 1
@@ -156,6 +173,9 @@ class ServeStats:
                 self.cache_hits += 1
             if degraded:
                 self.degraded += 1
+            if queue_wait_ms is not None:
+                self.queue_wait_ms_total += queue_wait_ms
+                self.queue_waits += 1
 
     def record_cache_miss(self) -> None:
         with self._lock:
@@ -211,14 +231,6 @@ class ServeStats:
         with self._lock:
             self.overfetch_saturated += n
 
-    def _snapshot(self) -> np.ndarray:
-        with self._lock:
-            return np.asarray(self.latencies_ms, dtype=np.float64)
-
-    def percentile(self, p: float) -> float:
-        lat = self._snapshot()
-        return float(np.percentile(lat, p)) if lat.size else 0.0
-
     def summary(self) -> dict:
         with self._lock:
             lat = np.asarray(self.latencies_ms, dtype=np.float64)
@@ -242,6 +254,8 @@ class ServeStats:
                 "compaction_failures": self.compaction_failures,
                 "last_compaction_ms": self.last_compaction_ms,
                 "overfetch_saturated": self.overfetch_saturated,
+                "queue_wait_ms_total": self.queue_wait_ms_total,
+                "queue_waits": self.queue_waits,
                 "bucket_batches": {f"{b}x{q}": n for (b, q), n in sorted(self.bucket_batches.items())},
                 "mean_ms": float(lat.mean()) if lat.size else 0.0,
                 "p50_ms": float(np.percentile(lat, 50)) if lat.size else 0.0,
@@ -383,6 +397,7 @@ class RetrievalEngine:
         self._q: queue.Queue = queue.Queue(maxsize=depth)  # interactive lane
         self._q_batch: queue.Queue = queue.Queue(maxsize=depth)  # batch lane
         self._seq = itertools.count()
+        self._batch_seq = itertools.count()  # the serve.batch span's batch_id
         self.admission = AdmissionController(admission) if admission is not None else None
         self.chaos = chaos
         self.slo = None
@@ -442,6 +457,10 @@ class RetrievalEngine:
         that expires pre-scoring resolves the future with ``DeadlineExceeded``."""
         t0 = time.monotonic()
         rid = request.request_id or f"req-{next(self._seq)}"
+        with TraceAnnotation("serve.admit", request_id=rid):
+            return self._admit(request, rid, t0)
+
+    def _admit(self, request: SearchRequest, rid: str, t0: float) -> Future:
         if self._stop.is_set():
             self.stats.record_rejected()
             raise EngineShutdown(
@@ -507,30 +526,43 @@ class RetrievalEngine:
             request_id=rid, expiry=expiry, lane=AdmissionController.lane(request.priority),
         )
         lane_q = self._q if item.lane == LANE_INTERACTIVE else self._q_batch
-        while True:
-            if self._stop.is_set():
-                self.stats.record_rejected()
-                raise EngineShutdown(
-                    f"RetrievalEngine is shut down; request {rid} rejected", request_id=rid
-                )
-            if item.expiry is not None and time.monotonic() > item.expiry:
-                # backpressure held the caller past its own deadline: fail fast
-                self.stats.record_deadline_expired()
-                _try_set_exception(fut, DeadlineExceeded(
-                    f"request {rid} deadline expired while blocked on backpressure",
-                    request_id=rid, deadline_ms=request.deadline_ms,
-                ))
-                return fut
-            try:
-                lane_q.put(item, timeout=0.05)
-                break
-            except queue.Full:
-                continue  # backpressure: hold the caller until the worker drains
+        queued = self._enqueue(lane_q, item, request.deadline_ms, timeout=0)
+        if queued is None:  # backpressure: hold the caller until the worker drains
+            with TraceAnnotation("serve.backpressure", request_id=rid):
+                while queued is None:
+                    queued = self._enqueue(lane_q, item, request.deadline_ms, timeout=0.05)
+        if not queued:
+            return fut
         if self._stop.is_set():
             self._drain()  # lost the race with shutdown's drain; fail it ourselves
         if self.slo is not None:
             self.slo.observe(self._qsize())  # queue growth degrades at admission speed
         return fut
+
+    def _enqueue(self, lane_q: queue.Queue, item: _Item, deadline_ms: Optional[float],
+                 timeout: float) -> Optional[bool]:
+        """One attempt to queue ``item``: True once queued, False once its deadline
+        has failed it (never queued), None while the lane stays full for
+        ``timeout`` seconds. Raises ``EngineShutdown`` once the engine is shut down."""
+        rid = item.request_id
+        if self._stop.is_set():
+            self.stats.record_rejected()
+            raise EngineShutdown(
+                f"RetrievalEngine is shut down; request {rid} rejected", request_id=rid
+            )
+        if item.expiry is not None and time.monotonic() > item.expiry:
+            # backpressure held the caller past its own deadline: fail fast
+            self.stats.record_deadline_expired()
+            _try_set_exception(item.fut, DeadlineExceeded(
+                f"request {rid} deadline expired while blocked on backpressure",
+                request_id=rid, deadline_ms=deadline_ms,
+            ))
+            return False
+        try:
+            lane_q.put(item, timeout=timeout)
+        except queue.Full:
+            return None
+        return True
 
     def submit(self, tids: np.ndarray, ws: np.ndarray) -> Future:
         """Deprecated raw-array entry point: Future of (ids [k], scores [k]) for
@@ -715,9 +747,11 @@ class RetrievalEngine:
     def _loop(self) -> None:
         try:
             while not self._stop.is_set():
-                items = self._collect()
+                with TraceAnnotation("serve.collect"):
+                    items = self._collect()
                 if items:
-                    self._serve_batch(items)
+                    with TraceAnnotation("serve.batch", batch_id=next(self._batch_seq)) as span:
+                        self._serve_batch(items, span)
         finally:
             # reached on clean shutdown AND when a programming error escapes
             # _serve_batch: mark the engine stopped and fail everything still
@@ -742,103 +776,116 @@ class RetrievalEngine:
                 live.append(it)
         return live
 
-    def _serve_batch(self, items: list) -> None:
-        items = self._expire(items)
-        if not items:
-            return
-        # snapshot (retriever, epoch) atomically: the whole batch scores on one index
-        # and its cache fills are keyed to that same index's epoch — a swap landing
-        # mid-batch neither mixes indexes nor lets old-index results into the new
-        # epoch's cache namespace
-        with self._retriever_lock:
-            retriever, epoch = self.retriever, self._epoch
-        dynamic = getattr(retriever, "supports_dynamic", False)
-        dflt = self._default_params(retriever) or DynamicParams()
-        bucket = self.ladder.select(len(items), max(len(it.tids) for it in items))
-        queries = [(it.tids, it.ws) for it in items]
-        while len(queries) < bucket.batch:
-            queries.append(_EMPTY_QUERY)
-        qb = make_query_batch(queries, self.vocab, nq_max=bucket.nq)
-        resolved = [it.eff or dflt for it in items]
+    def _serve_batch(self, items: list, span: TraceAnnotation) -> None:
+        """One batch in four stages, each a span inside ``span`` (``serve.batch``):
+        pad, dispatch, fetch (the device outputs copied to the host), resolve."""
+        with TraceAnnotation("serve.pad"):
+            items = self._expire(items)
+            if not items:
+                return
+            # snapshot (retriever, epoch) atomically: the whole batch scores on one
+            # index and its cache fills are keyed to that same index's epoch — a swap
+            # landing mid-batch neither mixes indexes nor lets old-index results into
+            # the new epoch's cache namespace
+            with self._retriever_lock:
+                retriever, epoch = self.retriever, self._epoch
+            dynamic = getattr(retriever, "supports_dynamic", False)
+            dflt = self._default_params(retriever) or DynamicParams()
+            bucket = self.ladder.select(len(items), max(len(it.tids) for it in items))
+            queries = [(it.tids, it.ws) for it in items]
+            while len(queries) < bucket.batch:
+                queries.append(_EMPTY_QUERY)
+            qb = make_query_batch(queries, self.vocab, nq_max=bucket.nq)
+            resolved = [it.eff or dflt for it in items]
+            # space-separated: the trace's metadata encoding ends a value at a comma
+            span.set_metadata(bucket=f"{bucket.batch}x{bucket.nq}", n=len(items),
+                              request_ids=" ".join(it.request_id for it in items))
         try:
-            if self.chaos is not None:
-                self.chaos.on_batch(len(items))  # may stall or raise: same isolation
-            if dynamic:
-                # mixed per-request overrides ride one program as per-row arrays
-                # (padding rows serve the defaults; their results are discarded)
-                row_params = resolved + [dflt] * (bucket.batch - len(items))
-                out = retriever(qb, row_params)
-            else:
-                out = retriever(qb)
-            # RetrievalResult (or any ids/scores-leading tuple) both unpack here
-            ids = np.asarray(out[0])
-            scores = np.asarray(out[1])
-            theta = getattr(out, "theta", None)
-            nsb = getattr(out, "n_superblocks_visited", None)
-            nblk = getattr(out, "n_blocks_scored", None)
-            shard_cand = getattr(out, "shard_candidates", None)
-            theta = None if theta is None else np.asarray(theta)
-            nsb = None if nsb is None else np.asarray(nsb)
-            nblk = None if nblk is None else np.asarray(nblk)
-            shard_cand = None if shard_cand is None else np.asarray(shard_cand)
-            # the delta seq this batch was ACTUALLY served at (stamped on the
-            # result from the adapter's atomic snapshot; 0 for immutable
-            # retrievers) — fills key on it, so keys are always truthful even
-            # when a mutation lands mid-batch
-            served_seq = int(getattr(out, "delta_seq", 0) or 0)
-            # rows whose tombstone overfetch clipped at k_max (0 for immutable
-            # retrievers): surfaced as a ServeStats counter so operators — and
-            # the freshness audit — see short-window hazards, not silence
-            saturated = int(getattr(out, "overfetch_saturated", 0) or 0)
+            with TraceAnnotation("serve.dispatch"):
+                t_dispatch = time.monotonic()
+                if self.chaos is not None:
+                    self.chaos.on_batch(len(items))  # may stall or raise: same isolation
+                if dynamic:
+                    # mixed per-request overrides ride one program as per-row arrays
+                    # (padding rows serve the defaults; their results are discarded)
+                    row_params = resolved + [dflt] * (bucket.batch - len(items))
+                    out = retriever(qb, row_params)
+                else:
+                    out = retriever(qb)
+            with TraceAnnotation("serve.fetch"):
+                # RetrievalResult (or any ids/scores-leading tuple) both unpack here
+                ids = np.asarray(out[0])
+                scores = np.asarray(out[1])
+                theta = getattr(out, "theta", None)
+                nsb = getattr(out, "n_superblocks_visited", None)
+                nblk = getattr(out, "n_blocks_scored", None)
+                shard_cand = getattr(out, "shard_candidates", None)
+                theta = None if theta is None else np.asarray(theta)
+                nsb = None if nsb is None else np.asarray(nsb)
+                nblk = None if nblk is None else np.asarray(nblk)
+                shard_cand = None if shard_cand is None else np.asarray(shard_cand)
+                # the delta seq this batch was ACTUALLY served at (stamped on the
+                # result from the adapter's atomic snapshot; 0 for immutable
+                # retrievers) — fills key on it, so keys are always truthful even
+                # when a mutation lands mid-batch
+                served_seq = int(getattr(out, "delta_seq", 0) or 0)
+                # rows whose tombstone overfetch clipped at k_max (0 for immutable
+                # retrievers): surfaced as a ServeStats counter so operators — and
+                # the freshness audit — see short-window hazards, not silence
+                saturated = int(getattr(out, "overfetch_saturated", 0) or 0)
         except _OPERATIONAL_ERRORS as exc:  # backend fault: fail this batch, keep serving
-            for it in items:
-                _try_set_exception(it.fut, exc)
-            self.stats.record_failures(len(items))
+            self._fail(items, exc)
             return
         except Exception as exc:  # programming error: fail the futures, then escalate
+            self._fail(items, exc)
+            raise
+        with TraceAnnotation("serve.resolve"):
+            now = time.monotonic()
+            for i, it in enumerate(items):
+                k_i = min(resolved[i].k, ids.shape[1]) if dynamic else ids.shape[1]
+                rec = _Record(
+                    ids=ids[i, :k_i].copy(),
+                    scores=scores[i, :k_i].copy(),
+                    theta=None if theta is None else float(theta[i]),
+                    nsb=None if nsb is None else int(nsb[i]),
+                    nblk=None if nblk is None else int(nblk[i]),
+                    params=resolved[i] if dynamic else it.eff,
+                    bucket=(bucket.batch, bucket.nq),
+                    shard_candidates=None if shard_cand is None else shard_cand[i].copy(),
+                    degraded=it.degraded,
+                )
+                if self.cache is not None and it.key is not None:
+                    # fill only while our epoch is still current (checked under the
+                    # flip lock): a batch that completes after a swap must not park
+                    # dead old-epoch rows in the LRU, where they would evict live
+                    # entries. The seq component is the one the batch was served at,
+                    # so a mutation landing mid-batch cannot make this fill lie —
+                    # probes after the mutation carry the newer seq and simply miss it
+                    with self._retriever_lock:
+                        if epoch == self._epoch:
+                            self.cache.put((epoch, served_seq, it.key), rec)
+                lat_ms = (now - it.t0) * 1e3
+                self.stats.record(lat_ms, degraded=it.degraded,
+                                  queue_wait_ms=(t_dispatch - it.t0) * 1e3)
+                if self.slo is not None:
+                    self.slo.record(lat_ms)
+                # _response_from copies: don't pin the batch array, and don't let the
+                # cached record alias the caller's result (a caller mutating
+                # ids/scores in place must not corrupt what later hits are served from)
+                _try_set_result(it.fut, _response_from(
+                    rec, epoch=epoch, cache_hit=False, delta_seq=served_seq
+                ))
+            if saturated:
+                self.stats.record_overfetch_saturated(saturated)
+            self.stats.record_batch(bucket)
+            if self.slo is not None:
+                self.slo.observe(self._qsize())  # served-latency view: recovery happens here
+
+    def _fail(self, items: list, exc: BaseException) -> None:
+        with TraceAnnotation("serve.resolve"):
             for it in items:
                 _try_set_exception(it.fut, exc)
             self.stats.record_failures(len(items))
-            raise
-        now = time.monotonic()
-        for i, it in enumerate(items):
-            k_i = min(resolved[i].k, ids.shape[1]) if dynamic else ids.shape[1]
-            rec = _Record(
-                ids=ids[i, :k_i].copy(),
-                scores=scores[i, :k_i].copy(),
-                theta=None if theta is None else float(theta[i]),
-                nsb=None if nsb is None else int(nsb[i]),
-                nblk=None if nblk is None else int(nblk[i]),
-                params=resolved[i] if dynamic else it.eff,
-                bucket=(bucket.batch, bucket.nq),
-                shard_candidates=None if shard_cand is None else shard_cand[i].copy(),
-                degraded=it.degraded,
-            )
-            if self.cache is not None and it.key is not None:
-                # fill only while our epoch is still current (checked under the flip
-                # lock): a batch that completes after a swap must not park dead
-                # old-epoch rows in the LRU, where they would evict live entries.
-                # The seq component is the one the batch was served at, so a
-                # mutation landing mid-batch cannot make this fill lie — probes
-                # after the mutation carry the newer seq and simply miss it
-                with self._retriever_lock:
-                    if epoch == self._epoch:
-                        self.cache.put((epoch, served_seq, it.key), rec)
-            lat_ms = (now - it.t0) * 1e3
-            self.stats.record(lat_ms, degraded=it.degraded)
-            if self.slo is not None:
-                self.slo.record(lat_ms)
-            # _response_from copies: don't pin the batch array, and don't let the
-            # cached record alias the caller's result (a caller mutating
-            # ids/scores in place must not corrupt what later hits are served from)
-            _try_set_result(it.fut, _response_from(
-                rec, epoch=epoch, cache_hit=False, delta_seq=served_seq
-            ))
-        if saturated:
-            self.stats.record_overfetch_saturated(saturated)
-        self.stats.record_batch(bucket)
-        if self.slo is not None:
-            self.slo.observe(self._qsize())  # served-latency view: recovery happens here
 
     def _drain(self) -> None:
         for lane_q in (self._q, self._q_batch):

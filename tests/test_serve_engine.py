@@ -8,6 +8,7 @@ from concurrent.futures import Future
 import numpy as np
 import pytest
 
+from repro.api import SearchRequest
 from repro.core import RetrievalConfig, jit_retrieve
 from repro.core.query import canonical_query, query_key
 from repro.serve import BucketLadder, QueryResultCache, RetrievalEngine
@@ -486,3 +487,103 @@ def test_concurrent_submit_shutdown_stress():
         exc = f.exception(timeout=30)
         assert exc is None or isinstance(exc, RuntimeError)
     assert any(f.exception(timeout=1) is None for f in futs)
+
+
+# ---- tracing: stage spans and the queue-wait counter --------------------------------
+
+_STAGES = ("serve.pad", "serve.dispatch", "serve.fetch", "serve.resolve")
+
+
+def _host_events(trace_dir):
+    """(line index, name, start, end, stats) of every host event in the trace."""
+    import jax
+
+    path = max(trace_dir.rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                out.append((i, e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+def test_stage_spans_in_profiler_trace(tmp_path):
+    import jax
+
+    entered, release = threading.Event(), threading.Event()
+
+    def gated(qb):
+        entered.set()
+        release.wait(timeout=30)
+        return _echo_retriever(qb)
+
+    eng = RetrievalEngine(gated, vocab=64, max_batch=1, nq_max=16, max_wait_ms=0.0,
+                          cache_size=0, queue_depth=1)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        q = [np.array([i + 1, i + 2], np.int32) for i in range(3)]
+        w = np.array([1.0, 0.5], np.float32)
+        futs = [eng.search(SearchRequest(q[0], w, request_id="a"))]
+        assert entered.wait(timeout=30)  # "a" is in service: the lane is free again
+        futs.append(eng.search(SearchRequest(q[1], w, request_id="b")))  # fills the lane
+        blocked = threading.Thread(
+            target=lambda: futs.append(eng.search(SearchRequest(q[2], w, request_id="c"))))
+        blocked.start()
+        time.sleep(0.2)  # "c" is held by backpressure
+        release.set()
+        blocked.join(timeout=30)
+        assert not blocked.is_alive()
+        assert [f.result(timeout=30).doc_ids[0] for f in futs] == [1, 2, 3]
+    finally:
+        release.set()
+        eng.shutdown()
+        jax.profiler.stop_trace()
+
+    events = _host_events(tmp_path)
+    names = {e[1] for e in events}
+    for name in ("serve.admit", "serve.backpressure", "serve.collect", "serve.batch") + _STAGES:
+        assert name in names, name
+    batches = [e for e in events if e[1] == "serve.batch"]
+    assert sorted(b[4]["request_ids"] for b in batches) == ["a", "b", "c"]
+    assert sorted(b[4]["batch_id"] for b in batches) == [0, 1, 2]
+    assert all(b[4]["bucket"] == "1x16" and b[4]["n"] == 1 for b in batches)
+    for line, _, lo, hi, _ in batches:
+        inside = sorted((s, e, n) for i, n, s, e, _ in events
+                        if i == line and n in _STAGES and lo <= s and e <= hi)
+        assert tuple(n for _, _, n in inside) == _STAGES  # one each, in order
+        assert all(a[1] <= b[0] for a, b in zip(inside, inside[1:]))
+    (held,) = [e for e in events if e[1] == "serve.backpressure"]
+    assert held[4]["request_id"] == "c" and held[3] > held[2]
+    assert any(i == held[0] and n == "serve.admit" and st["request_id"] == "c"
+               and s <= held[2] and held[3] <= e for i, n, s, e, st in events)
+
+
+def test_queue_wait_counts_admission_to_dispatch():
+    service_s = 0.5
+    entered = threading.Event()
+
+    def slow(qb):
+        entered.set()
+        time.sleep(service_s)
+        return _echo_retriever(qb)
+
+    eng = RetrievalEngine(slow, vocab=64, max_batch=1, nq_max=16, max_wait_ms=0.0,
+                          cache_size=16)
+    try:
+        rng = np.random.default_rng(0)
+        a, b = _query(rng, vocab=64), _query(rng, vocab=64)
+        fa = eng.search(SearchRequest(*a))
+        assert entered.wait(timeout=30)
+        fb = eng.search(SearchRequest(*b))  # waits out the rest of a's service
+        fa.result(timeout=30), fb.result(timeout=30)
+        assert eng.search(SearchRequest(*a)).result(timeout=30).cache_hit  # never queued
+        s = eng.stats.summary()
+        lat_ms = sum(eng.stats.latencies_ms)
+    finally:
+        eng.shutdown()
+    assert s["requests"] == 3 and s["cache_hits"] == 1 and s["queue_waits"] == 2
+    assert s["queue_wait_ms_total"] >= 0.5 * service_s * 1e3
+    # each device-scored latency holds its queue wait and then a whole service
+    assert lat_ms - s["queue_wait_ms_total"] >= 2 * 0.99 * service_s * 1e3
