@@ -60,13 +60,15 @@ def score_gather(
     blk_ids: jnp.ndarray,
     layout: str = "fwd",
     impl: str = "auto",
+    blk_mask: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """Per-document scores of the selected blocks: [Q, S] block ids -> [Q, S, b].
 
     The single dispatch point for document scoring (round-0 superblock expansion and
     phase-3 block scoring both route here). ``qb`` is the full (unpruned) query.
     Scores carry the per-block dequant scales; padded/ineligible blocks are NOT
-    masked here — that is score_blocks' job.
+    masked here — that is score_blocks' job. The kernels skip the slots where
+    ``blk_mask`` [Q, S] is false (they score 0 there); the reference ignores it.
     """
     operand = index.docs_flatq if layout == "flat" else index.docs_fwdq
     assert operand is not None, (
@@ -87,4 +89,4 @@ def score_gather(
     from repro.kernels.doc_score.ops import doc_score_flat_op, doc_score_fwd_op
 
     op = doc_score_flat_op if layout == "flat" else doc_score_fwd_op
-    return op(operand, qb.tids, qb.ws, blk_ids, interpret=mode == "interpret")
+    return op(operand, qb.tids, qb.ws, blk_ids, interpret=mode == "interpret", blk_mask=blk_mask)

@@ -53,11 +53,11 @@ def score_blocks(
     """Score all docs of selected blocks. blk_ids/blk_mask [Q, S] -> ([Q, S*b], pos).
 
     Masked blocks and padded docs (remap sentinel) score NEG so they never reach
-    top-k. One call serves both layouts and both impls (ref / Pallas kernel);
-    ``qb`` is the full (unpruned) query.
+    top-k; the kernels do no work for masked blocks. One call serves both layouts
+    and both impls (ref / Pallas kernel); ``qb`` is the full (unpruned) query.
     """
     b = index.b
-    scores = ops.score_gather(index, qb, blk_ids, layout, impl)  # [Q, S, b]
+    scores = ops.score_gather(index, qb, blk_ids, layout, impl, blk_mask)  # [Q, S, b]
     pos = blk_ids[:, :, None] * b + jnp.arange(b)[None, None, :]  # [Q, S, b]
     n_pad = index.doc_remap.shape[0]
     valid = index.doc_remap[jnp.clip(pos, 0, n_pad - 1)] < index.n_docs

@@ -2,6 +2,7 @@
 
 Applies the per-block dequant scales (kernels are scale-free) and clamps block ids;
 callers mask padded/ineligible blocks downstream (repro.core.scoring.score_blocks).
+Given that mask, the kernels skip its dead slots (``live_ids``): they score 0.
 A kernel call scalar-prefetches the [Q, S] block ids into SMEM (1 MiB on a v5e), so a
 batch whose ids exceed ``SMEM_BLOCK_IDS`` runs as consecutive calls over query groups.
 """
@@ -39,33 +40,56 @@ def per_query_groups(kernel, operands, q_tids, q_ws, blk_c, interpret, budget=SM
     return out.reshape(n * rows, *out.shape[2:])[:q]
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def _call_fwd(tids3, ws3, scales, q_tids, q_ws, blk_ids, interpret):
-    blk_c = jnp.clip(blk_ids, 0, tids3.shape[0] - 1).astype(jnp.int32)
+def live_ids(blk_c, blk_mask):
+    """Clamped block ids [Q, S] with each dead slot (``blk_mask`` false) as ``-1 - id``,
+    ``id`` the nearest live block before it in its row (the row's first live block
+    before its first live slot; the row's first slot where none is live). Runs of dead
+    slots then name one block, which the kernel's pipeline fetches once. ``None``:
+    every slot is live."""
+    if blk_mask is None:
+        return blk_c
+    slot = jnp.arange(blk_c.shape[1], dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(blk_mask, slot, -1), axis=1)  # last live slot so far
+    first = jnp.argmax(blk_mask, axis=1).astype(jnp.int32)[:, None]
+    fill = jnp.take_along_axis(blk_c, jnp.where(last < 0, first, last), axis=1)
+    return jnp.where(blk_mask, blk_c, -1 - fill)
+
+
+def _call(kernel, operands, scales, q_tids, q_ws, blk_ids, blk_mask, interpret):
+    blk_c = jnp.clip(blk_ids, 0, scales.shape[0] - 1).astype(jnp.int32)
     raw = per_query_groups(
-        doc_score_fwd_pallas, (tids3, ws3),
-        q_tids.astype(jnp.int32), q_ws.astype(jnp.float32), blk_c, interpret,
+        kernel, operands, q_tids.astype(jnp.int32), q_ws.astype(jnp.float32),
+        live_ids(blk_c, blk_mask), interpret,
     )
     return raw * scales[blk_c][:, :, None]
 
 
-def doc_score_fwd_op(fwdq: FwdDocsQ, q_tids, q_ws, blk_ids, interpret: bool = False) -> jnp.ndarray:
-    """[Q, S] selected blocks of the query (q_tids, q_ws) -> scaled scores float32 [Q, S, b]."""
-    return _call_fwd(fwdq.tids, fwdq.ws, fwdq.scales, q_tids, q_ws, blk_ids, interpret)
+@partial(jax.jit, static_argnames=("interpret",))
+def _call_fwd(tids3, ws3, scales, q_tids, q_ws, blk_ids, blk_mask, interpret):
+    return _call(doc_score_fwd_pallas, (tids3, ws3), scales, q_tids, q_ws, blk_ids, blk_mask,
+                 interpret)
+
+
+def doc_score_fwd_op(
+    fwdq: FwdDocsQ, q_tids, q_ws, blk_ids, interpret: bool = False, blk_mask=None
+) -> jnp.ndarray:
+    """[Q, S] selected blocks of the query (q_tids, q_ws) -> scaled scores float32 [Q, S, b].
+    Slots where the optional bool [Q, S] ``blk_mask`` is false are skipped and score 0."""
+    return _call_fwd(fwdq.tids, fwdq.ws, fwdq.scales, q_tids, q_ws, blk_ids, blk_mask, interpret)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
-def _call_flat(tids, ws, doc_ends, scales, q_tids, q_ws, blk_ids, interpret):
-    blk_c = jnp.clip(blk_ids, 0, tids.shape[0] - 1).astype(jnp.int32)
-    raw = per_query_groups(
-        doc_score_flat_pallas, (tids, ws, doc_ends),
-        q_tids.astype(jnp.int32), q_ws.astype(jnp.float32), blk_c, interpret,
-    )
-    return raw * scales[blk_c][:, :, None]
+def _call_flat(tids, ws, doc_ends, scales, q_tids, q_ws, blk_ids, blk_mask, interpret):
+    return _call(doc_score_flat_pallas, (tids, ws, doc_ends), scales, q_tids, q_ws, blk_ids,
+                 blk_mask, interpret)
 
 
-def doc_score_flat_op(flatq: FlatDocsQ, q_tids, q_ws, blk_ids, interpret: bool = False) -> jnp.ndarray:
-    """[Q, S] selected blocks of the query (q_tids, q_ws) -> scaled scores float32 [Q, S, b]."""
+def doc_score_flat_op(
+    flatq: FlatDocsQ, q_tids, q_ws, blk_ids, interpret: bool = False, blk_mask=None
+) -> jnp.ndarray:
+    """[Q, S] selected blocks of the query (q_tids, q_ws) -> scaled scores float32 [Q, S, b].
+    Slots where the optional bool [Q, S] ``blk_mask`` is false are skipped and score 0."""
     return _call_flat(
-        flatq.tids, flatq.ws, flatq.doc_ends, flatq.scales, q_tids, q_ws, blk_ids, interpret
+        flatq.tids, flatq.ws, flatq.doc_ends, flatq.scales, q_tids, q_ws, blk_ids, blk_mask,
+        interpret,
     )

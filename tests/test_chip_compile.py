@@ -87,10 +87,11 @@ def _sds_index(sharding, n_docs: int = N_DOCS):
 def _kernel_call(name, sharding):
     """(fn, shape args) of one kernel at the smoke's widths and k=10 budgets.
     "doc_score_fwd_64q" is the ops wrapper at 64 queries, whose block ids outgrow
-    SMEM and run over query groups."""
+    SMEM and run over query groups; the "_masked" wrappers take phase 3's block
+    mask and skip its dead slots."""
     from repro.kernels.boundsum_gather.kernel import boundsum_gather_pallas
     from repro.kernels.doc_score.kernel import doc_score_flat_pallas, doc_score_fwd_pallas
-    from repro.kernels.doc_score.ops import doc_score_fwd_op
+    from repro.kernels.doc_score.ops import doc_score_flat_op, doc_score_fwd_op
     from repro.kernels.sbmax.kernel import sbmax_pallas
 
     ix = _sds_index(sharding)
@@ -115,11 +116,23 @@ def _kernel_call(name, sharding):
             lambda fq, t, w, blk: doc_score_fwd_op(fq, t, w, blk),
             (fq, s((64, NQ), jnp.int32), s((64, NQ), jnp.float32), s((64, scored), jnp.int32)),
         ),
+        "doc_score_fwd_masked": (
+            lambda fq, t, w, blk, m: doc_score_fwd_op(fq, t, w, blk, blk_mask=m),
+            (fq, *q_terms, s((Q, scored), jnp.int32), s((Q, scored), jnp.bool_)),
+        ),
+        "doc_score_flat_masked": (
+            lambda flq, t, w, blk, m: doc_score_flat_op(flq, t, w, blk, blk_mask=m),
+            (flq, *q_terms, s((Q, scored), jnp.int32), s((Q, scored), jnp.bool_)),
+        ),
     }[name]
 
 
 @pytest.mark.parametrize(
-    "name", ["sbmax", "boundsum_gather", "doc_score_fwd", "doc_score_flat", "doc_score_fwd_64q"]
+    "name",
+    [
+        "sbmax", "boundsum_gather", "doc_score_fwd", "doc_score_flat", "doc_score_fwd_64q",
+        "doc_score_fwd_masked", "doc_score_flat_masked",
+    ],
 )
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, args = _kernel_call(name, one_chip)

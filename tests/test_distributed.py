@@ -93,6 +93,51 @@ def test_sharded_retriever_mesh_bit_identical_to_hostloop_and_single():
     assert "OK" in out
 
 
+def test_sharded_doc_score_kernel_bit_identical_to_single_ref():
+    """Both transports with the doc_score kernel (interpret mode) return the
+    single-device reference's answers bit for bit. Their block masks are not
+    prefixes: round 0 keeps the owned superblocks of the global top-γ₀, phase 3
+    the η-cut survivors (γ = NS: most slots dead) or the owned members of the
+    global block budget. The bound kernels are steered to the reference, so the
+    two sides differ only in document scoring."""
+    out = _run(
+        """
+        import numpy as np
+        from repro.core import ops
+        from repro.data.synthetic import CorpusConfig, make_corpus, make_queries
+        from repro.index.builder import IndexBuildConfig, build_index
+        from repro.core import RetrievalConfig, make_query_batch, retrieve
+        from repro.distributed.sharded import ShardedRetriever
+        from repro.launch.mesh import make_host_mesh
+        sbmax, gathered = ops.sbmax, ops.gathered_block_bounds
+        ops.sbmax = lambda pb, t, w, impl="auto": sbmax(pb, t, w, "ref")
+        ops.gathered_block_bounds = (
+            lambda pb, c, t, w, sel, impl="auto": gathered(pb, c, t, w, sel, "ref"))
+        ccfg = CorpusConfig(n_docs=2500, vocab=512, n_topics=8, seed=0)
+        corpus = make_corpus(ccfg)
+        idx = build_index(corpus.doc_ptr, corpus.tids, corpus.ws, corpus.vocab,
+                          IndexBuildConfig(b=8, c=8, kmeans_iters=2))
+        qb = make_query_batch(make_queries(ccfg, corpus, 8), corpus.vocab)
+        ns = idx.n_superblocks
+        for kw in ({}, dict(block_budget=17)):
+            cfg = RetrievalConfig(variant="lsp0", k=10, gamma=ns, gamma0=2, beta=0.5, **kw)
+            ref = retrieve(idx, qb, cfg, impl="ref")
+            for name, sr in (
+                ("host", ShardedRetriever(idx, cfg, n_shards=3, impl="kernel")),
+                ("mesh", ShardedRetriever(idx, cfg, n_shards=2,
+                                          mesh=make_host_mesh(model=2, data=2), impl="kernel")),
+            ):
+                res = sr(qb)
+                for f in ("doc_ids", "scores", "theta", "n_superblocks_visited",
+                          "n_blocks_scored"):
+                    a, b = np.asarray(getattr(ref, f)), np.asarray(getattr(res, f))
+                    assert (a == b).all(), (kw, name, f)
+        print("OK")
+        """
+    )
+    assert "OK" in out
+
+
 @pytest.mark.slow
 def test_vocab_parallel_embedding_matches_local():
     out = _run(
